@@ -8,6 +8,8 @@ import time
 
 def main() -> None:
     t_start = time.time()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.extensions import EXTENSION_BENCHMARKS
     from benchmarks.kernel_bench import (bench_engine, bench_kernels,
                                          bench_paged_kv)

@@ -27,6 +27,7 @@ NEG_INF = -1e30
 def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, window: Optional[int],
             nk: int):
+    bi = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -41,20 +42,20 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qpos = qpos_ref[0, 0]                            # scalar
-    kpos = kpos_ref[0]                               # (bk,)
+    qpos = qpos_ref[bi]                              # scalar (SMEM)
+    kpos = kpos_ref[0]                               # (1, bk)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window is not None:
         valid &= kpos > qpos - window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid[None, :], p, 0.0)
+    m_prev = m_ref[...]                              # (g, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(valid, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -63,7 +64,7 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     def _done():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
@@ -85,27 +86,33 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
     nk = kt.shape[2] // block_k
 
     qg = q.reshape(b, nkv, g, hd)
-    qp2 = q_pos[:, None].astype(jnp.int32)            # (b, 1)
+    qp = q_pos.astype(jnp.int32)                      # (b,) scalar prefetch
+    kp = kp.astype(jnp.int32)[:, None, :]             # (b, 1, S')
 
-    grid = (b, nkv, nk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nkv, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_k), lambda bi, h, j, p: (bi, 0, j)),
+            pl.BlockSpec((1, 1, g, hd), lambda bi, h, j, p: (bi, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda bi, h, j, p: (bi, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda bi, h, j, p: (bi, h, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda bi, h, j, p: (bi, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+        ],
+    )
     kern = functools.partial(_kernel, scale=hd ** -0.5, window=window, nk=nk)
     out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, h, j: (bi, 0)),
-            pl.BlockSpec((1, block_k), lambda bi, h, j: (bi, j)),
-            pl.BlockSpec((1, 1, g, hd), lambda bi, h, j: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda bi, h, j: (bi, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda bi, h, j: (bi, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, h, j: (bi, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
         interpret=interpret,
-    )(qp2, kp, qg, kt, vt)
+    )(qp, kp, qg, kt, vt)
     return out.reshape(b, nq, hd)

@@ -1,10 +1,11 @@
 """Kernel dispatch policy.
 
 ``use_pallas()`` decides whether the model routes attention / SSD through
-the Pallas kernels. On this CPU container the kernels run in
+the Pallas kernels. On a TPU it is on and ``interpret()`` is off, so the
+compiled kernels run. Anywhere else the kernels can only run in
 ``interpret=True`` mode (Python emulation — correct but slow), so the
-default is the pure-jnp reference path; set ``REPRO_USE_PALLAS=1`` (or on
-a real TPU it flips automatically) to exercise the kernels end-to-end.
+default there is the pure-jnp reference path; set ``REPRO_USE_PALLAS=1``
+to exercise the kernels end-to-end in interpret mode.
 """
 from __future__ import annotations
 

@@ -40,13 +40,13 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qpos = qpos_ref[0]                               # (bq,)
-    kpos = kpos_ref[0]                               # (bk,)
-    valid = (kpos[None, :] >= 0) & (qpos[:, None] >= 0)
+    qpos = qpos_ref[0]                               # (bq, 1)
+    kpos = kpos_ref[0]                               # (1, bk)
+    valid = (kpos >= 0) & (qpos >= 0)
     if causal:
-        valid &= kpos[None, :] <= qpos[:, None]
+        valid &= kpos <= qpos
         if window is not None:
-            valid &= kpos[None, :] > qpos[:, None] - window
+            valid &= kpos > qpos - window
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -89,8 +89,10 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
     qt = pad_to(jnp.moveaxis(q, 2, 1), block_q, 2)   # (b, nq, s', hd)
     kt = pad_to(jnp.moveaxis(k, 2, 1), block_k, 2)   # (b, nkv, S', hd)
     vt = pad_to(jnp.moveaxis(v, 2, 1), block_k, 2)
-    qp = pad_to(q_pos, block_q, 1, -1)
-    kp = pad_to(kv_pos, block_k, 1, -1)
+    # positions as a (b, s', 1) column and a (b, 1, S') row: the blocks'
+    # last two dims then satisfy the TPU tiling rule at any batch size
+    qp = pad_to(q_pos.astype(jnp.int32), block_q, 1, -1)[:, :, None]
+    kp = pad_to(kv_pos.astype(jnp.int32), block_k, 1, -1)[:, None, :]
     sp, Sp = qt.shape[2], kt.shape[2]
     ni, nk = sp // block_q, Sp // block_k
 
@@ -101,8 +103,8 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda bi, h, i, j: (bi, i)),
-            pl.BlockSpec((1, block_k), lambda bi, h, i, j: (bi, j)),
+            pl.BlockSpec((1, block_q, 1), lambda bi, h, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, h, i, j: (bi, 0, j)),
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, h, i, j: (bi, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda bi, h, i, j: (bi, h // g, j, 0)),

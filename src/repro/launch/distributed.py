@@ -1,10 +1,10 @@
 """Multi-host bootstrap for real pod deployments.
 
 On real TPU slices, each host process calls :func:`ensure_initialized`
-before touching jax devices; the coordinator address / process ids come
-from the environment set by ``launch/pod.sh``. On the CPU dev container
-this is a no-op (single process) so every driver can call it
-unconditionally.
+before touching jax devices; the coordinator address, process count and
+process id come from ``JAX_COORDINATOR_ADDRESS``, ``JAX_NUM_PROCESSES``
+and ``JAX_PROCESS_ID``. With one process this is a no-op, so every
+driver can call it unconditionally.
 """
 from __future__ import annotations
 
@@ -16,7 +16,7 @@ _initialized = False
 
 
 def ensure_initialized() -> bool:
-    """Initialize jax.distributed from pod.sh's environment. Returns True
+    """Initialize jax.distributed from the environment. Returns True
     if a multi-process runtime was set up."""
     global _initialized
     if _initialized:

@@ -378,7 +378,11 @@ class Engine:
         self.max_batch = max_batch
         self.max_len = max_len
         self.cache_dtype = cache_dtype
-        self.kv_dtype = kv_dtype          # e.g. jnp.float8_e4m3fn (§Perf)
+        # attention KV is stored in the parameters' dtype unless asked
+        # otherwise (e.g. jnp.float8_e4m3fn): a bf16 model gets bf16 pages
+        self.kv_dtype = kv_dtype
+        if kv_dtype is None and params is not None:
+            self.kv_dtype = params["embed"].dtype
         self.paged = paged
         self.page_size = page_size
         self.chunked_prefill = chunked_prefill
@@ -412,7 +416,7 @@ class Engine:
                                  metrics=self.metrics, name=name)
             self.caches = make_caches(
                 cfg, max_batch, max_len, dtype=cache_dtype,
-                kv_dtype=kv_dtype, layout="paged", page_size=page_size,
+                kv_dtype=self.kv_dtype, layout="paged", page_size=page_size,
                 n_pages=n_pool_pages)
             self._prefill = make_prefill_fn(cfg, donate_caches=True)
             self._insert_side = make_paged_insert_fn(cfg)
@@ -426,7 +430,8 @@ class Engine:
             self._prefill = make_prefill_fn(cfg)
             self._insert = make_insert_fn(cfg)
             self.caches = make_caches(cfg, max_batch, max_len,
-                                      dtype=cache_dtype, kv_dtype=kv_dtype)
+                                      dtype=cache_dtype,
+                                      kv_dtype=self.kv_dtype)
         self.prefix_cache: Optional[PrefixCache] = None
         self._prefill_suffix = None
         if prefix_cache or chunked_prefill:

@@ -378,3 +378,22 @@ def test_dense_insert_dtype_cast_and_seq_pad(smollm):
     assert int(out["len"][1]) == 7
     # untouched slots stay zero
     np.testing.assert_array_equal(np.asarray(out["attn"][0].k[:, 0]), 0.0)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_kv_dtype_follows_param_dtype(smollm, paged):
+    """A bf16 model gets bf16 KV in every engine of a cluster; an explicit
+    kv_dtype still wins."""
+    from repro.core.cluster import EPDCluster
+    from repro.models.model import init_params
+    from repro.serving.engine import Engine
+    cfg, _ = smollm
+    params = init_params(cfg, KEY, dtype=jnp.bfloat16)
+    cluster = EPDCluster(cfg, params, max_batch=2, max_len=32, paged=paged)
+    for eng in (cluster.prefill_engine, *cluster.decode_engines):
+        for e in eng.caches["attn"]:
+            if e is not None:
+                assert e.k.dtype == e.v.dtype == jnp.bfloat16
+    eng = Engine(cfg, params, max_batch=1, max_len=32, paged=paged,
+                 kv_dtype=jnp.float32)
+    assert eng.caches["attn"][0].k.dtype == jnp.float32
