@@ -48,7 +48,7 @@ def bench_observability(trace_path: Optional[str] = None) -> List[str]:
     # ---- 1. REAL cluster: traced chaos run + invariants --------------------
     cfg = get_config("smollm-135m").reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
-    tracer = Tracer(enabled=True, decode_sample=2)
+    tracer = Tracer(enabled=True)
     cl = EPDCluster(cfg, params, max_batch=2, max_len=96, paged=True,
                     page_size=8, prefix_cache=True, chunked_prefill=True,
                     prefill_chunk=8,

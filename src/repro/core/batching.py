@@ -33,6 +33,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+from repro.core.telemetry import MetricsRegistry
 from repro.serving.request import Request
 
 
@@ -152,7 +153,8 @@ class IterationScheduler:
                  chunk_budget_tokens: Optional[int] = None,
                  adaptive_chunking: bool = False,
                  min_chunk_budget: int = 16,
-                 max_chunk_budget: int = 1 << 20):
+                 max_chunk_budget: int = 1 << 20,
+                 metrics: Optional[MetricsRegistry] = None):
         if max_live_prefills < 1:
             raise ValueError("need max_live_prefills >= 1")
         self.max_live_prefills = max_live_prefills
@@ -175,7 +177,11 @@ class IterationScheduler:
         self.ready: Deque[PrefillJob] = deque()
         self._rr = 0
         self.steps = 0
+        # stalls by reason: this scheduler's own, and the serving
+        # system's registry (``sched_stalls_total{reason=...}``), whose
+        # counts outlive one drain
         self.stall_counts: Dict[str, int] = {}
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
 
     # ---- intake / state transitions (executor-driven) ----
     def submit(self, job: PrefillJob) -> PrefillJob:
@@ -209,6 +215,7 @@ class IterationScheduler:
 
     def note_stall(self, job: PrefillJob, reason: str) -> None:
         self.stall_counts[reason] = self.stall_counts.get(reason, 0) + 1
+        self.metrics.counter("sched_stalls_total", reason=reason).inc()
 
     # ---- introspection ----
     @property

@@ -40,8 +40,7 @@ from repro.core.faults import (DEFAULT_RETRY, NO_RETRY, NoFreeSlot,
 from repro.core.scheduler import Router
 from repro.core.ep_prefetch import EPPrefetcher
 from repro.core.events import EventLoop
-from repro.core.kv_transfer import (TransferPlan, emit_spans,
-                                    plan as kv_plan,
+from repro.core.kv_transfer import (TransferPlan, plan as kv_plan,
                                     plan_chunked as kv_plan_chunked)
 from repro.core.mm_store import MMStore
 from repro.core.telemetry import (NULL_TRACER, LatencyAccountant,
@@ -132,15 +131,12 @@ class EPDCluster:
         # telemetry plane: one metrics registry + one span tracer + one
         # latency accountant for the whole cluster. The accountant's
         # clock is wall time (sync at every state transition) PLUS
-        # modeled charges (transfer exposure, retry backoff) — the same
-        # virtual timebase retry_time accounting already used; the
-        # tracer is re-clocked onto it so wall spans and modeled
-        # transfer spans share one timeline.
+        # modeled charges (transfer exposure, retry backoff); the tracer
+        # keeps the host's clock and records only what ran on this host,
+        # so its spans line up with a profiler trace of the device.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.acc = LatencyAccountant(wall=time.perf_counter)
-        if tracer is not None:
-            tracer.set_clock(self.acc.clock)
         self._queue_since: Dict[int, float] = {}
         # one fault plane across every failure domain: store fetches,
         # transfer groups, decode instances, and the swap tier all draw
@@ -267,7 +263,7 @@ class EPDCluster:
         ``queue`` and the wait start is remembered for the queue span."""
         self.acc.set_state(req.request_id, "queue")
         if self.tracer.enabled:
-            self._queue_since.setdefault(req.request_id, self.acc.clock())
+            self._queue_since.setdefault(req.request_id, self.tracer.now())
 
     def _unpark_queued(self, req: Request) -> None:
         """A queued request starts service: close its queue-wait span
@@ -275,7 +271,7 @@ class EPDCluster:
         self.acc.set_state(req.request_id, "compute")
         t0 = self._queue_since.pop(req.request_id, None)
         if t0 is not None and self.tracer.enabled:
-            self.tracer.add("queue.wait", t0, self.acc.clock(),
+            self.tracer.add("queue.wait", t0, self.tracer.now(),
                             track="router", request_id=req.request_id)
 
     def attribution(self) -> Dict[str, Any]:
@@ -341,7 +337,7 @@ class EPDCluster:
             self.metrics.counter("encode_skips_total").inc()
             self._encode_skipped.add(req.request_id)
             if self.tracer.enabled:
-                t = self.acc.clock()
+                t = self.tracer.now()
                 self.tracer.add("encode.skip", t, t, track=eng.name,
                                 request_id=req.request_id)
             return key
@@ -388,14 +384,9 @@ class EPDCluster:
                                scheduling_latency_hint=hint)
         self._ep_loop.run()
         self.acc.sync()
-        t0 = self.acc.now
         self.acc.advance(extra, req.request_id, "transfer")
         if self.timeline is not None and extra > 0:
             self.timeline.charge_encode(extra)
-        if self.tracer.enabled and extra > 0:
-            self.tracer.add("ep.prefetch", t0, self.acc.now, track="store",
-                            request_id=req.request_id,
-                            mode=self.ep_overlap, nbytes=nbytes)
 
     # ---- Prefill stage (with FT retry + recompute on store miss) ----
     def prefill(self, req: Request, key: Optional[str]):
@@ -421,15 +412,9 @@ class EPDCluster:
             self.metrics.counter("recovery_retries_total",
                                  site=SITE_STORE_FETCH).inc()
             # backoff is modeled time: charge it to the request's
-            # retry component and render it on the store track
+            # retry component
             self.acc.sync()
-            t0 = self.acc.now
             self.acc.advance(back, req.request_id, "retry")
-            if self.tracer.enabled:
-                self.tracer.add("retry.store", t0, self.acc.now,
-                                track="store",
-                                request_id=req.request_id,
-                                attempt=attempt)
             feats = self.store.get(key, record=False, attempt=attempt)
             attempt += 1
         if feats is None:
@@ -516,20 +501,12 @@ class EPDCluster:
         # into it by recovery) is modeled time — the real arrays move
         # in-process. Charge it on the accounting clock: retry time to
         # the retry component, the remaining exposure to transfer. The
-        # modeled group schedule is anchored so its prefill_end lands
-        # at the current accounting now (the real prefill just ended on
-        # the wall clock).
+        # plan itself stays in report.kv_plans.
         self.acc.sync()
-        base = self.acc.now - p.prefill_end
         retry_t = rec.retry_time if rec is not None else 0.0
         exposed = max(0.0, p.exposed_latency - retry_t)
         self.acc.advance(retry_t, req.request_id, "retry")
         self.acc.advance(exposed, req.request_id, "transfer")
-        emit_spans(self.tracer, p, base=base,
-                   handshake=self.cost.hw.handshake,
-                   compute_track=self.prefill_engine.name,
-                   link_track=f"{self.prefill_engine.name}->{engine.name}",
-                   request_id=req.request_id, recovery=rec)
         # insert may preempt a decode victim to make room; only a
         # successful admission records the transfer plan
         engine.insert(req, caches, first, append_token=append_token)
@@ -608,7 +585,7 @@ class EPDCluster:
         self.metrics.counter("instance_crashes_total",
                              engine=eng.name).inc()
         if self.tracer.enabled:
-            t = self.acc.clock()
+            t = self.tracer.now()
             self.tracer.add("crash", t, t, track=eng.name,
                             harvested=len(inflight))
         for req in inflight:
@@ -861,10 +838,13 @@ class EPDCluster:
         barrier = "ready_at" if job.meta.get("monolithic") \
             else "feature_ready_at"
         attempt = job.meta.get("store_attempts", 0)
-        feats = self.store.get(key, record=False, attempt=attempt)
-        if feats is not None:
-            job.meta["mm_feats"] = jnp.asarray(feats)[None]
-            return True
+        # the store fetch and the features' copy to the device
+        with self.tracer.span("ep.fetch", track=self.prefill_engine.name,
+                              request_id=rid):
+            feats = self.store.get(key, record=False, attempt=attempt)
+            if feats is not None:
+                job.meta["mm_feats"] = jnp.asarray(feats)[None]
+                return True
         attempt += 1
         job.meta["store_attempts"] = attempt
         base = max(tl.t_prefill, job.ready_at, job.feature_ready_at)
@@ -876,12 +856,7 @@ class EPDCluster:
             self.metrics.counter("recovery_retries_total",
                                  site=SITE_STORE_FETCH).inc()
             self.acc.sync()
-            t0 = self.acc.now
             self.acc.advance(back, rid, "retry")
-            if self.tracer.enabled:
-                self.tracer.add("retry.store", t0, self.acc.now,
-                                track="store", request_id=rid,
-                                attempt=attempt)
             setattr(job, barrier, nxt)
             sched.note_stall(job, "store_retry")
             return False
@@ -1052,12 +1027,7 @@ class EPDCluster:
             self.metrics.counter("sched_retry_parks_total",
                                  engine=self.prefill_engine.name).inc()
             self.acc.sync()
-            t0 = self.acc.now
             self.acc.advance(back, rid, "retry")
-            if self.tracer.enabled:
-                self.tracer.add("retry.transfer", t0, self.acc.now,
-                                track="router", request_id=rid,
-                                attempt=attempt)
             sched.park_ready(job, nxt)
             return None
         self._count_transfer_recovery(rec)
@@ -1195,7 +1165,8 @@ class EPDCluster:
                 max_live_prefills = 4
         sched = IterationScheduler(max_live_prefills=max_live_prefills,
                                    chunk_budget_tokens=chunk_budget_tokens,
-                                   adaptive_chunking=adaptive_chunking)
+                                   adaptive_chunking=adaptive_chunking,
+                                   metrics=self.metrics)
         # the engine's page_holders audits scheduler-held payloads
         # (ready-but-unadmitted prefills) through this reference; the
         # cluster-level handle lets benches/tests read step and stall
@@ -1229,8 +1200,9 @@ class EPDCluster:
             active = sum(self.decode_engines[i].n_active
                          + len(self.decode_engines[i].preempted)
                          for i in self.live_decode_indices())
-            plan = sched.plan(now=tl.t_prefill, free_slots=free,
-                              active_decode=active)
+            with self.tracer.span("sched.plan", track="router"):
+                plan = sched.plan(now=tl.t_prefill, free_slots=free,
+                                  active_decode=active)
             progressed = 0
             n_admitted = n_chunked = 0
             with self.tracer.span("sched.step", track="router",
@@ -1297,42 +1269,44 @@ class EPDCluster:
                     done, tl, router, sched)
                 if decoded:
                     progressed += 1
-            # same scheduler telemetry the fused-engine execute_plan
-            # emits, labeled on the Prefill instance driving the loop
-            M = self.metrics
-            M.counter("sched_steps_total", engine=pe.name).inc()
-            if n_chunked:
-                M.counter("sched_chunks_total",
-                          engine=pe.name).inc(n_chunked)
-            if n_admitted:
-                M.counter("sched_admissions_total",
-                          engine=pe.name).inc(n_admitted)
-            if n_chunked and (n_admitted or decoded):
-                M.counter("sched_mixed_steps_total", engine=pe.name).inc()
-            if not progressed:
-                # nothing executed: either some job waits on a FUTURE
-                # arrival (jump the modeled clock to the earliest one —
-                # a pool-stalled job's elapsed barrier must not mask a
-                # parked job's retry_at, or the retry never matures and
-                # its payload pages deadlock the pool), or the prefill
-                # pool is deadlocked by partial in-flight tasks (abort
-                # the youngest and requeue it)
-                t = sched.next_barrier_time(after=tl.t_prefill)
-                if t is not None:
-                    tl.t_prefill = t
-                elif not self._restart_one_prefill(sched):
-                    raise RuntimeError(
-                        f"continuous scheduler deadlock at step "
-                        f"{plan.step} (stalls: {sched.stall_counts})")
-            self.acc.sync()
-            for eng in self.decode_engines:
-                eng.drain_notes()
-            pe.drain_notes()
-            if not sched.has_prefill_work:
-                # prefill stream drained: collapse the Router's stale
-                # busy_until so the replica reads idle again
-                router.on_idle(pe.name, tl.t_prefill)
+            with self.tracer.span("loop.bookkeeping", track="router"):
+                # same scheduler telemetry the fused-engine execute_plan
+                # emits, labeled on the Prefill instance driving the loop
+                M = self.metrics
+                M.counter("sched_steps_total", engine=pe.name).inc()
+                if n_chunked:
+                    M.counter("sched_chunks_total",
+                              engine=pe.name).inc(n_chunked)
+                if n_admitted:
+                    M.counter("sched_admissions_total",
+                              engine=pe.name).inc(n_admitted)
+                if n_chunked and (n_admitted or decoded):
+                    M.counter("sched_mixed_steps_total", engine=pe.name).inc()
+                if not progressed:
+                    # nothing executed: either some job waits on a FUTURE
+                    # arrival (jump the modeled clock to the earliest one —
+                    # a pool-stalled job's elapsed barrier must not mask a
+                    # parked job's retry_at, or the retry never matures and
+                    # its payload pages deadlock the pool), or the prefill
+                    # pool is deadlocked by partial in-flight tasks (abort
+                    # the youngest and requeue it)
+                    t = sched.next_barrier_time(after=tl.t_prefill)
+                    if t is not None:
+                        tl.t_prefill = t
+                    elif not self._restart_one_prefill(sched):
+                        raise RuntimeError(
+                            f"continuous scheduler deadlock at step "
+                            f"{plan.step} (stalls: {sched.stall_counts})")
+                self.acc.sync()
+                for eng in self.decode_engines:
+                    eng.drain_notes()
+                pe.drain_notes()
+                if not sched.has_prefill_work:
+                    # prefill stream drained: collapse the Router's stale
+                    # busy_until so the replica reads idle again
+                    router.on_idle(pe.name, tl.t_prefill)
             if on_step is not None:
-                on_step(steps)
+                with self.tracer.span("loop.on_step", track="router"):
+                    on_step(steps)
         self._finalize(done)
         return done

@@ -287,7 +287,9 @@ class TransferRecovery:
 
     handshake_faults: int = 0
     wire_faults: int = 0
-    retries: int = 0              # failed attempts that were retried
+    # failed attempts that another attempt followed: in place, or in
+    # the replan of an exhausted group
+    retries: int = 0
     retry_time: float = 0.0       # backoff + wasted handshake/wire time
     replanned_groups: int = 0     # groups delivered via the fresh replan
     deadline_hits: int = 0        # groups whose retry budget ran out
@@ -399,6 +401,8 @@ def recover_plan(plan: TransferPlan, *, injector: FaultInjector,
         # fresh attempt budgets, scheduled after the surviving traffic
         rec.replanned_groups = len(missing)
         for g in missing:
+            # the group's last failed attempt is retried by the replan
+            rec.retries += 1
             got, clock, spent = _attempt_group(
                 g, clock, injector=injector, policy=policy,
                 handshake=handshake, link_bw=link_bw, key=key,

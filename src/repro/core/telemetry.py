@@ -10,16 +10,19 @@ time), three layers deep:
 
 * :class:`Tracer` — an allocation-light span recorder.
   ``tracer.span(name, request_id=..., **attrs)`` is a context manager
-  around a pipeline phase (a prefill chunk, a decode step, a swap-out);
-  ``tracer.add(...)`` records spans with *modeled* timestamps (transfer
-  groups, retry backoffs — things that never run on this host's clock).
-  A disabled tracer (the default) returns a shared no-op context
-  manager: zero allocations, zero recorded spans, zero behavior change.
-  Spans carry a ``track`` (one per engine instance / link) so the
-  Chrome-trace exporter (``core.trace_export``) renders one timeline
-  row per instance.
+  around a pipeline phase (a prefill chunk, a decode step, a swap-out)
+  measured on the tracer's clock; an enabled tracer also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so that in a
+  profiler trace every span lies on the host's timeline beside the
+  device's programs. ``tracer.add(...)`` records a span with explicit
+  timestamps: the simulator's modeled intervals, or a wait whose ends
+  the caller read from ``tracer.now()``. A disabled tracer (the
+  default) returns a shared no-op context manager: zero allocations,
+  zero recorded spans, zero behavior change. Spans carry a ``track``
+  (one per engine instance / link) so the Chrome-trace exporter
+  (``core.trace_export``) renders one timeline row per instance.
 
-* :class:`MetricsRegistry` — labeled counters / gauges / histograms.
+* :class:`MetricsRegistry` — labeled counters and gauges.
   The ad-hoc counters that used to live on ``Engine`` (refault pages,
   swap totals), ``ClusterReport`` (retry counts, retry time) and
   ``PagePool`` (peak occupancy) now live here under stable names; the
@@ -38,7 +41,7 @@ time), three layers deep:
   ``mark_first_token`` snapshots the components at the TTFT gate,
   giving separate TTFT and TPOT decompositions.
 
-:func:`quantile` is the one histogram-quantile implementation (linear
+:func:`quantile` is the one quantile implementation (linear
 interpolation, correct at n == 0 and n == 1) reused by ``SimMetrics``
 and the benchmark suite.
 """
@@ -69,8 +72,8 @@ def quantile(xs, p: float) -> float:
     Correct at the edges the old ad-hoc helpers got wrong: an empty
     input returns 0.0 (not an IndexError), a single sample returns that
     sample for every ``p``, and ``p`` outside [0, 1] clamps. This is
-    the single implementation behind ``Histogram.quantile``,
-    ``SimMetrics`` p99s, and the benchmark reports.
+    the single implementation behind ``SimMetrics`` p99s and the
+    benchmark reports.
     """
     xs = sorted(xs)
     n = len(xs)
@@ -126,37 +129,6 @@ class Gauge:
             self.value = float(v)
 
 
-class Histogram:
-    """Exact-sample histogram: stores observations, answers quantiles
-    via :func:`quantile`. Fine at serving-benchmark cardinalities; a
-    production system would swap in fixed buckets behind the same API."""
-
-    __slots__ = ("name", "labels", "values")
-
-    def __init__(self, name: str, labels: Tuple[Tuple[str, str], ...]):
-        self.name = name
-        self.labels = labels
-        self.values: List[float] = []
-
-    def observe(self, v: float) -> None:
-        self.values.append(float(v))
-
-    @property
-    def count(self) -> int:
-        return len(self.values)
-
-    @property
-    def sum(self) -> float:
-        return float(sum(self.values))
-
-    @property
-    def mean(self) -> float:
-        return self.sum / self.count if self.values else 0.0
-
-    def quantile(self, p: float) -> float:
-        return quantile(self.values, p)
-
-
 def _label_key(labels: Dict[str, Any]) -> Tuple[Tuple[str, str], ...]:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
 
@@ -169,7 +141,7 @@ def _fmt_key(name: str, labels: Tuple[Tuple[str, str], ...]) -> str:
 
 
 class MetricsRegistry:
-    """Get-or-create registry of labeled metrics.
+    """Get-or-create registry of labeled counters and gauges.
 
     ``registry.counter("kv_transfer_retries", site="transfer.wire")``
     returns the same Counter object on every call with the same name
@@ -200,9 +172,6 @@ class MetricsRegistry:
     def gauge(self, name: str, **labels) -> Gauge:
         return self._get(Gauge, name, labels)
 
-    def histogram(self, name: str, **labels) -> Histogram:
-        return self._get(Histogram, name, labels)
-
     def value(self, name: str, **labels) -> float:
         """Current value of a counter/gauge (0.0 when never touched)."""
         m = self._metrics.get((name, _label_key(labels)))
@@ -211,25 +180,16 @@ class MetricsRegistry:
     def total(self, name: str) -> float:
         """Sum of a counter/gauge across all its label sets."""
         return sum(m.value for (n, _), m in self._metrics.items()
-                   if n == name and not isinstance(m, Histogram))
+                   if n == name)
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-able dump: every metric keyed ``name{k=v,...}``. This is
         what benchmarks embed under the ``"telemetry"`` key so bench
         deltas can diff component-level counters, not just wall clocks."""
-        out: Dict[str, Any] = {"counters": {}, "gauges": {}, "histograms": {}}
+        out: Dict[str, Any] = {"counters": {}, "gauges": {}}
         for (name, labels), m in sorted(self._metrics.items()):
-            key = _fmt_key(name, labels)
-            if isinstance(m, Counter):
-                out["counters"][key] = m.value
-            elif isinstance(m, Gauge):
-                out["gauges"][key] = m.value
-            else:
-                out["histograms"][key] = {
-                    "count": m.count, "sum": m.sum, "mean": m.mean,
-                    "p50": m.quantile(0.50), "p99": m.quantile(0.99),
-                    "max": max(m.values) if m.values else 0.0,
-                }
+            kind = "counters" if isinstance(m, Counter) else "gauges"
+            out[kind][_fmt_key(name, labels)] = m.value
         return out
 
 
@@ -240,8 +200,8 @@ class MetricsRegistry:
 @dataclass
 class Span:
     """One closed interval on one track. ``start``/``end`` are seconds
-    on the tracer's clock (wall, accounting, or simulated — the track's
-    spans share a timebase, which is all the exporter needs)."""
+    on the tracer's clock (the host's ``perf_counter`` on the real
+    cluster, simulated time in the simulator)."""
 
     name: str
     track: str
@@ -271,9 +231,13 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+# span attributes that also go to the profiler's annotation
+_ANNOTATED = ("step", "chunk")
+
+
 class _SpanCM:
     __slots__ = ("_tracer", "_name", "_track", "_rid", "_attrs", "_start",
-                 "_parent")
+                 "_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
                  rid: Optional[int], attrs: Dict[str, Any]):
@@ -284,6 +248,13 @@ class _SpanCM:
         self._attrs = attrs
 
     def __enter__(self):
+        # imported here: the simulator records spans without JAX
+        from jax.profiler import TraceAnnotation
+        meta = {k: self._attrs[k] for k in _ANNOTATED if k in self._attrs}
+        if self._rid is not None:
+            meta["request_id"] = self._rid
+        self._annotation = TraceAnnotation(self._name, **meta)
+        self._annotation.__enter__()
         t = self._tracer
         self._parent = t._stack[-1] if t._stack else None
         t._stack.append(self._name)
@@ -292,22 +263,26 @@ class _SpanCM:
 
     def __exit__(self, *exc):
         t = self._tracer
+        end = t.now()
         t._stack.pop()
-        t.spans.append(Span(self._name, self._track, self._start, t.now(),
+        t.spans.append(Span(self._name, self._track, self._start, end,
                             self._rid, self._parent, self._attrs))
+        self._annotation.__exit__(*exc)
         return False
 
 
 class Tracer:
     """Span recorder. ``enabled=False`` (the default everywhere) makes
     ``span()`` return a shared no-op context manager — no allocation,
-    no clock read — so tracing can stay compiled into every hot path.
+    no annotation, no clock read — so tracing can stay compiled into
+    every hot path.
 
-    ``now`` is the clock: wall time by default, the cluster's accounting
-    clock or the simulator's event-loop time when those own the run
-    (``set_clock``). ``decode_sample`` thins the highest-frequency span
-    family: engines record one batched ``decode_step`` span every N
-    steps instead of every step.
+    ``now`` is the clock: the host's ``time.perf_counter`` by default,
+    the simulator's event-loop time when it owns the run
+    (``set_clock``). ``decode_sample`` thins the simulator's
+    highest-frequency span family: one modeled ``decode.step`` span
+    every N decode iterations (``want_decode_span``). The real engines
+    record every decode step.
     """
 
     def __init__(self, enabled: bool = False,
@@ -334,9 +309,10 @@ class Tracer:
     def add(self, name: str, start: float, end: float, track: str = "main",
             request_id: Optional[int] = None, parent: Optional[str] = None,
             **attrs) -> None:
-        """Record a span with explicit timestamps — modeled timelines
-        (transfer-group schedules, retry backoffs, simulator service
-        times) that never ran on this host's clock."""
+        """Record a span with explicit timestamps: the simulator's
+        modeled timelines (transfer-group schedules, service times), or
+        an interval whose ends the caller read from ``now()`` (a queue
+        wait). Not mirrored to the profiler."""
         if not self.enabled:
             return
         if end < start:
@@ -472,17 +448,6 @@ class LatencyAccountant:
         self._alias: Dict[int, int] = {}
 
     # -- clock ----------------------------------------------------------------
-    def clock(self) -> float:
-        """Continuous view of the accounting clock: ``now`` plus the
-        wall time elapsed since the last ``sync()`` (as if a sync
-        happened this instant). Bind this as the tracer clock so spans
-        recorded between syncs land on the same timebase as modeled
-        transfer/retry spans. Monotone: ``sync`` folds the elapsed
-        segment into ``now`` and resets the reference point."""
-        if self._wall is None:
-            return self.now
-        return self.now + max(0.0, self._wall() - self._last)
-
     def sync(self) -> None:
         if self._wall is None:
             return

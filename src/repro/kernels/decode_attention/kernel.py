@@ -111,6 +111,7 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
     kern = functools.partial(_kernel, scale=hd ** -0.5, window=window, nk=nk)
     out = pl.pallas_call(
         kern,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, g, hd), q.dtype),
         interpret=interpret,

@@ -101,6 +101,7 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, window: Optional[int] = None,
                              window=window, nk=nk)
     out = pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, 1), lambda bi, h, i, j: (bi, i, 0)),

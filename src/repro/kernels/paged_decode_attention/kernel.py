@@ -138,6 +138,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tbl, lengths, *,
                              n_pages_max=n_pages_max)
     return pl.pallas_call(
         kern,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, hd), q.dtype),
         interpret=interpret,

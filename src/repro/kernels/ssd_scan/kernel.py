@@ -98,6 +98,7 @@ def ssd_scan(x, dt, a, b, c, d_skip, chunk: int,
     kern = functools.partial(_kernel, nc=nc)
     y, fin = pl.pallas_call(
         kern,
+        name="ssd_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda bi, h, ci: (bi, h, ci, 0, 0)),

@@ -1190,23 +1190,22 @@ class Engine:
         Preempted requests are re-admitted first (FIFO, page-permitting)
         so a resumed slot decodes in this very step.
 
-        Decode spans are SAMPLED: one ``decode.step`` span every
-        ``tracer.decode_sample`` steps (this is the highest-frequency
-        phase; per-step spans at production rates would dominate the
-        trace)."""
+        With tracing on, every step records a ``decode.step`` span whose
+        children time each host phase: ``decode.resume``,
+        ``decode.len_sync``, ``decode.grow_pages``, ``decode.key_split``,
+        ``decode.dispatch``, ``decode.readback`` and ``decode.commit``."""
         if self.crashed:
             raise InstanceDown(self.name, 0)
         self._decode_steps += 1
-        if self.tracer.want_decode_span(self._decode_steps):
-            with self.tracer.span("decode.step", track=self.name,
-                                  step=self._decode_steps,
-                                  batch=self.n_active):
-                return self._decode_step_inner()
-        return self._decode_step_inner()
+        with self.tracer.span("decode.step", track=self.name,
+                              step=self._decode_steps, batch=self.n_active):
+            return self._decode_step_inner()
 
     def _decode_step_inner(self) -> List[Tuple[Request, int, bool]]:
+        span, track = self.tracer.span, self.name
         if self.paged and self.preempted:
-            self.try_resume()
+            with span("decode.resume", track=track):
+                self.try_resume()
         if self.n_active == 0:
             # idle-batch early-out: with zero active slots the jitted
             # forward would compute only trash-page rows — skip the
@@ -1215,34 +1214,41 @@ class Engine:
             # decodes this very step.)
             return []
         # single device->host sync per step (not per slot)
-        lens = np.asarray(self.caches["len"])
+        with span("decode.len_sync", track=track):
+            lens = np.asarray(self.caches["len"])
         if self.paged:
-            self._grow_pages(lens)
-        self._key, sub = jax.random.split(self._key)
-        toks, self.caches = self._decode(
-            self.params, jnp.asarray(self._last_tok), self.caches, sub)
-        toks = np.asarray(toks)
+            with span("decode.grow_pages", track=track):
+                self._grow_pages(lens)
+        with span("decode.key_split", track=track):
+            self._key, sub = jax.random.split(self._key)
+        with span("decode.dispatch", track=track):
+            toks, self.caches = self._decode(
+                self.params, jnp.asarray(self._last_tok), self.caches, sub)
+        with span("decode.readback", track=track):
+            toks = np.asarray(toks)
         out = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            t = int(toks[i])
-            self._last_tok[i] = t
-            req.output_tokens.append(t)
-            # lens[i] is the PRE-step resident length: this step's KV
-            # landed at index lens[i], so the cache now holds lens[i]+1
-            # tokens and the next step would write at lens[i]+1 — done
-            # exactly when that would spill past max_len (the cache can
-            # fill to the last position, no give-away row).
-            done = (t == req.eos_token or
-                    len(req.output_tokens) >= req.max_new_tokens or
-                    int(lens[i]) + 1 >= self.max_len)
-            if done:
-                self.slots[i] = None
-                self._resume_marks.pop(req.request_id, None)
-                if self.paged:
-                    self._release_slot(i)
-            out.append((req, t, done))
+        with span("decode.commit", track=track):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                t = int(toks[i])
+                self._last_tok[i] = t
+                req.output_tokens.append(t)
+                # lens[i] is the PRE-step resident length: this step's
+                # KV landed at index lens[i], so the cache now holds
+                # lens[i]+1 tokens and the next step would write at
+                # lens[i]+1 — done exactly when that would spill past
+                # max_len (the cache can fill to the last position, no
+                # give-away row).
+                done = (t == req.eos_token or
+                        len(req.output_tokens) >= req.max_new_tokens or
+                        int(lens[i]) + 1 >= self.max_len)
+                if done:
+                    self.slots[i] = None
+                    self._resume_marks.pop(req.request_id, None)
+                    if self.paged:
+                        self._release_slot(i)
+                out.append((req, t, done))
         return out
 
     # -- continuous batching (iteration-level scheduling, fused PD) -----------
@@ -1276,7 +1282,7 @@ class Engine:
         this fused engine; ``step()`` drains the queue. The scheduler is
         created on first use — engines never pay for it otherwise."""
         if self.scheduler is None:
-            self.scheduler = IterationScheduler()
+            self.scheduler = IterationScheduler(metrics=self.metrics)
         n_mm = mm_feats.shape[1] if mm_feats is not None else (
             req.mm_tokens if mm_key is not None else 0)
         job = PrefillJob(

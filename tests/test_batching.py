@@ -332,3 +332,169 @@ def test_cluster_continuous_accepts_fault_plans(smollm):
     cl.prefill_engine.assert_no_page_leaks()
     for d in cl.decode_engines:
         d.assert_no_page_leaks()
+
+
+# ---------------------------------------------------------------------------
+# serving-loop spans: the host's clock, measured intervals only
+# ---------------------------------------------------------------------------
+
+def _mm_reqs():
+    return [Request(prompt_tokens=list(range(1, 20)), max_new_tokens=5,
+                    mm_payload=b"imgA", mm_tokens=8, mm_pos=4),
+            Request(prompt_tokens=list(range(3, 30)), max_new_tokens=5),
+            Request(prompt_tokens=list(range(1, 24)), max_new_tokens=6,
+                    mm_payload=b"imgB", mm_tokens=8, mm_pos=4),
+            Request(prompt_tokens=list(range(9, 40)), max_new_tokens=4)]
+
+
+@pytest.fixture(scope="module")
+def llava():
+    cfg = get_config("llava-next-mistral-7b").reduced()
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def traced_run(llava):
+    """One traced continuous run of an image + text mix: the cluster,
+    its requests, the run's host-clock start and its iterations."""
+    import time
+    from types import SimpleNamespace
+    from repro.core.telemetry import Tracer
+    cfg, params = llava
+    cl = _cluster(cfg, params, max_batch=2, prefix_cache=True,
+                  ep_overlap="async", tracer=Tracer(enabled=True))
+    reqs = _mm_reqs()
+    iters = []
+    t0 = time.perf_counter()
+    done = cl.run_continuous(reqs, on_step=iters.append)
+    assert len(done) == len(reqs)
+    cl.tracer.assert_balanced()
+    return SimpleNamespace(cl=cl, reqs=reqs, t0=t0, n_iters=len(iters))
+
+
+def test_every_decode_step_has_its_phase_spans(traced_run):
+    cl = traced_run.cl
+    eng = cl.decode_engines[0]
+    spans = [s for s in cl.tracer.spans if s.track == eng.name]
+    steps = [s for s in spans if s.name == "decode.step"]
+    assert len(steps) == eng._decode_steps > 0
+    phases = ("decode.len_sync", "decode.grow_pages", "decode.key_split",
+              "decode.dispatch", "decode.readback", "decode.commit")
+    children = [s for s in spans if s.name.startswith("decode.")
+                and s.name != "decode.step"]
+    assert {s.name for s in children} == set(phases)
+    assert all(s.parent == "decode.step" for s in children)
+    for st in steps:
+        inside = [s.name for s in children
+                  if st.start <= s.start and s.end <= st.end]
+        assert inside == list(phases)          # once each, in order
+
+
+def test_serving_loop_phases_are_spans(traced_run):
+    """Each iteration of run_continuous records its plan, its step, its
+    bookkeeping and the caller's hook, all on the router's track."""
+    cl, n_iters = traced_run.cl, traced_run.n_iters
+    count = {}
+    for s in cl.tracer.spans:
+        if s.track == "router":
+            count[s.name] = count.get(s.name, 0) + 1
+    for name in ("sched.plan", "sched.step", "loop.bookkeeping",
+                 "loop.on_step"):
+        assert count[name] == n_iters, name
+    assert count["queue.wait"] == 4
+
+
+def test_one_queue_wait_per_request_ends_at_its_first_chunk(traced_run):
+    spans = traced_run.cl.tracer.spans
+    for r in traced_run.reqs:
+        waits = [s for s in spans if s.name == "queue.wait"
+                 and s.request_id == r.request_id]
+        chunks = [s for s in spans if s.name == "prefill.chunk"
+                  and s.request_id == r.request_id]
+        assert len(waits) == 1 and chunks
+        assert traced_run.t0 <= waits[0].start <= waits[0].end \
+            <= chunks[0].start
+
+
+def test_image_features_are_fetched_under_a_span(traced_run):
+    fetched = {s.request_id for s in traced_run.cl.tracer.spans
+               if s.name == "ep.fetch"}
+    assert fetched == {r.request_id for r in traced_run.reqs
+                       if r.mm_payload}
+
+
+@pytest.mark.parametrize("driver", ["cluster", "engine"])
+def test_stall_counter_matches_stall_counts(smollm, llava, driver):
+    """Every stall the scheduler notes also lands in the registry under
+    ``sched_stalls_total{reason=...}``, reason by reason."""
+    if driver == "cluster":
+        cfg, params = llava
+        cl = _cluster(cfg, params, max_batch=2, prefix_cache=True,
+                      ep_overlap="async")
+        cl.run_continuous(_mm_reqs())
+        sched, metrics = cl.continuous_scheduler, cl.metrics
+    else:
+        cfg, params = smollm
+        eng = _engine(cfg, params, chunked_prefill=True, prefill_chunk=16)
+        for k, p in enumerate(PROMPTS):
+            # request k waits for clock k; the clock steps by a quarter
+            eng.submit(Request(prompt_tokens=p, max_new_tokens=6),
+                       ready_at=float(k))
+        clock = iter(range(10_000))
+        eng.drain_continuous(now_fn=lambda: float(next(clock)) / 4)
+        sched, metrics = eng.scheduler, eng.metrics
+    assert sched.stall_counts
+    for reason, n in sched.stall_counts.items():
+        assert metrics.value("sched_stalls_total", reason=reason) == n
+    assert metrics.total("sched_stalls_total") == \
+        sum(sched.stall_counts.values())
+
+
+@pytest.mark.parametrize("driver", ["serial", "continuous"])
+def test_real_cluster_spans_are_measured_on_the_host_clock(llava, driver):
+    """Under faults that charge modeled transfer exposure and backoff
+    (which the accountant adds to its own clock), every span still lies
+    inside the run's host-clock interval, and none is a modeled
+    transfer, prefetch or retry interval."""
+    import time
+    from repro.core.faults import (SITE_STORE_FETCH,
+                                   SITE_TRANSFER_HANDSHAKE,
+                                   SITE_TRANSFER_WIRE, FaultPlan)
+    from repro.core.telemetry import Tracer
+    cfg, params = llava
+    plan = FaultPlan(seed=5, rates={SITE_TRANSFER_WIRE: 0.4,
+                                    SITE_TRANSFER_HANDSHAKE: 0.3,
+                                    SITE_STORE_FETCH: 0.5})
+    tr = Tracer(enabled=True)
+    cl = _cluster(cfg, params, max_batch=2, prefix_cache=True,
+                  ep_overlap="async", faults=plan, tracer=tr)
+    reqs = _mm_reqs()
+    t0 = time.perf_counter()
+    if driver == "serial":
+        for r in reqs:
+            cl.submit(r)
+        done = cl.run_until_done()
+    else:
+        done = cl.run_continuous(reqs)
+    t1 = time.perf_counter()
+    assert len(done) == len(reqs)
+    # the faults drew: modeled retry and transfer time was charged
+    assert cl.report.retry_time_total > 0
+    assert cl.acc.component_total("transfer") > 0
+    assert tr.spans
+    for s in tr.spans:
+        assert t0 <= s.start <= s.end <= t1, s
+    modeled = {"kv.handshake", "kv.wire", "ep.prefetch"}
+    assert not [s for s in tr.spans
+                if s.name in modeled or s.name.startswith(("retry.",
+                                                           "kv.retry."))]
+
+
+def test_simulator_still_records_modeled_transfer_spans():
+    from repro.core.simulator import SHAREGPT_4O, simulate
+    from repro.core.telemetry import Tracer
+    tr = Tracer(enabled=True)
+    simulate(get_config("deepseek-7b"), "E-P-D", SHAREGPT_4O, rate=2.0,
+             n_requests=4, seed=3, kv_page_tokens=16, tracer=tr)
+    names = {s.name for s in tr.spans}
+    assert {"kv.handshake", "kv.wire"} <= names

@@ -311,7 +311,7 @@ def test_overlap_arms_bit_identical_and_accounted(llava):
         row = cl.attribution()["requests"][0]
         xfer[arm] = row["components_ms"]["transfer"]
         if arm != "inline":
-            assert any(s.name == "ep.prefetch" for s in tr.spans)
+            assert xfer[arm] > 0    # the modeled E->P hand-off is charged
         cl.prefill_engine.assert_no_page_leaks()
         cl.decode_engine.assert_no_page_leaks()
     mono = Engine(cfg, params, max_batch=2, max_len=96)

@@ -85,7 +85,7 @@ def test_recover_plan_conserves_payload_or_raises_typed(
     assert out.prefill_end == p.prefill_end
     assert out.kv_latency >= p.kv_latency
     assert out.exposed_latency >= p.exposed_latency
-    assert rec.retries >= rec.faults - rec.replanned_groups * 0
+    assert rec.retries >= rec.faults
     assert rec.retry_time >= 0.0
     if rec.faults == 0:
         assert out is p

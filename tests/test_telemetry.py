@@ -81,17 +81,16 @@ def test_gauge_max_high_water_mark():
 
 
 def test_snapshot_shape_and_histogram():
+    """A snapshot holds counters and gauges, keyed ``name{k=v}``, and
+    nothing else: the registry keeps no histograms."""
     r = MetricsRegistry()
     r.counter("c_total", k="v").inc()
     r.gauge("g").set(0.5)
-    h = r.histogram("lat_ms")
-    for v in (1.0, 2.0, 3.0):
-        h.observe(v)
     snap = r.snapshot()
-    assert snap["counters"]["c_total{k=v}"] == 1.0
-    assert snap["gauges"]["g"] == 0.5
-    hs = snap["histograms"]["lat_ms"]
-    assert hs["count"] == 3 and hs["sum"] == 6.0 and hs["p50"] == 2.0
+    assert set(snap) == {"counters", "gauges"}
+    assert snap["counters"] == {"c_total{k=v}": 1.0}
+    assert snap["gauges"] == {"g": 0.5}
+    assert not hasattr(r, "histogram")
     json.dumps(snap)                    # JSON-able
 
 
@@ -99,7 +98,35 @@ def test_snapshot_shape_and_histogram():
 # tracer
 # ---------------------------------------------------------------------------
 
-def test_disabled_tracer_records_nothing():
+class AnnotationRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    annotation's name and metadata as it is entered and left."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name, **meta):
+        rec = self
+
+        class _Annotation:
+            def __enter__(self):
+                rec.events.append(("enter", name, meta))
+
+            def __exit__(self, *exc):
+                rec.events.append(("exit", name, meta))
+
+        return _Annotation()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+    rec = AnnotationRecorder()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def test_disabled_tracer_records_nothing(annotations):
     t = Tracer(enabled=False)
     cm = t.span("phase", track="x", request_id=1)
     assert cm is NULL_SPAN              # shared no-op, zero allocation
@@ -108,6 +135,27 @@ def test_disabled_tracer_records_nothing():
     t.add("modeled", 0.0, 1.0)
     assert t.spans == []
     assert not t.want_decode_span(0)
+    assert annotations.events == []     # nothing reaches the profiler
+
+
+def test_enabled_tracer_mirrors_each_span_into_the_profiler(annotations):
+    """Each span enters one profiler annotation of the same name, with
+    the request id and the step or chunk index as its metadata; ``add``
+    reaches only the tracer."""
+    clk = {"t": 0.0}
+    t = Tracer(enabled=True, now=lambda: clk["t"])
+    with t.span("sched.step", track="router", step=4, n_chunks=2):
+        with t.span("prefill.chunk", track="P0", request_id=9, chunk=1,
+                    tokens=16):
+            clk["t"] = 1.0
+    t.add("queue.wait", 0.0, 1.0, track="router", request_id=9)
+    assert annotations.events == [
+        ("enter", "sched.step", {"step": 4}),
+        ("enter", "prefill.chunk", {"chunk": 1, "request_id": 9}),
+        ("exit", "prefill.chunk", {"chunk": 1, "request_id": 9}),
+        ("exit", "sched.step", {"step": 4})]
+    assert [s.name for s in t.spans] == ["prefill.chunk", "sched.step",
+                                         "queue.wait"]
 
 
 def test_span_nesting_records_parent():

@@ -111,13 +111,11 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip):
     _assert_pool_view_is_bitcast(hlo)
 
 
-def test_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def decode_step_hlo(one_chip):
     """The model's whole paged decode step as the chip deployment runs
-    it: llava-next-mistral-7b at published widths, 8 layers, bf16, the
-    compiled kernels. Inside the layer scan too, each layer's pool slice
-    reaches the kernel through a bitcast."""
-    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
-    monkeypatch.setattr(dispatch, "interpret", lambda: False)
+    it, compiled: llava-next-mistral-7b at published widths, 8 layers,
+    bf16, the compiled kernels."""
     cfg = dataclasses.replace(get_config("llava-next-mistral-7b"),
                               n_layers=8)
     params = jax.eval_shape(
@@ -133,9 +131,29 @@ def test_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     params, caches = jax.tree.map(on_chip, (params, caches))
     tokens = jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32, sharding=one_chip)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    hlo = make_decode_fn(cfg).lower(params, tokens, caches,
-                                    key).compile().as_text()
-    _assert_pool_view_is_bitcast(hlo)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_USE_PALLAS", "1")
+        mp.setattr(dispatch, "interpret", lambda: False)
+        return make_decode_fn(cfg).lower(params, tokens, caches,
+                                         key).compile().as_text()
+
+
+def test_paged_decode_step_compiles_for_v5e(decode_step_hlo):
+    """Inside the layer scan too, each layer's pool slice reaches the
+    kernel through a bitcast."""
+    _assert_pool_view_is_bitcast(decode_step_hlo)
+
+
+def test_paged_decode_step_names_its_kernel(decode_step_hlo):
+    """A profiler trace names each op by its HLO instruction: inside the
+    layer scan the paged kernel's call is ``paged_decode_attention.N``,
+    the ``name`` its ``pallas_call`` gives, so a trace says which kernel
+    ran."""
+    names = [m.group(1) for line in decode_step_hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             for m in [_INSTR.match(line)] if m]
+    assert names
+    assert all(n.startswith("paged_decode_attention") for n in names), names
 
 
 def test_decode_attention_compiles_for_v5e(one_chip):
