@@ -261,22 +261,48 @@ def run_decoder(params, cfg: ModelConfig, x, positions, *,
     pages = caches.get("pages") if caches is not None else None
     attn_cls = L.PagedAttnCache if pages is not None else L.AttnCache
     decode = caches is not None and x.shape[1] == 1
+    # Paged decode threads each attention pool through the scan's CARRY,
+    # flattened (R, P, page, nkv, hd) -> (R * P, page, nkv, hd) (a
+    # bitcast): repeat r's pages are r * P + the block table's entries, so
+    # a layer writes its one new token into the pool in place and the
+    # kernel reads its pages from the whole pool. As xs/ys, every layer's
+    # slab would be sliced out, re-stacked and copied back on every step.
+    pooled = decode and pages is not None
+    n_rep = cfg.n_repeats
+    pools = ()
+    if pooled:
+        pools = tuple(None if c is None else
+                      tuple(a.reshape(-1, *a.shape[2:]) for a in c)
+                      for c in caches["attn"])
 
     def body(carry, xs):
-        h, aux = carry
-        p_list, attn_c, ssm_c, cross_c = xs
+        h, aux, pools = carry
+        p_list, attn_c, ssm_c, cross_c, r = xs
         new_attn, new_ssm = [], []
+        new_pools = list(pools)
         new_cross = None
         for i, spec in enumerate(pat):
             p = p_list[i]
             if spec.mixer in ("attn", "swa"):
                 window = cfg.sliding_window if spec.mixer == "swa" else None
+                if pooled:
+                    # pool pages of repeat r; page r * P is its trash page
+                    cache = pools[i]
+                    layer_pages = pages + r * (cache[0].shape[0] // n_rep)
+                else:
+                    cache = (tuple(attn_c[i]) if attn_c[i] is not None
+                             else None)
+                    layer_pages = pages
                 h, nc = L.attention_block(
                     p["attn"], h, positions, cfg, window=window,
-                    cache=tuple(attn_c[i]) if attn_c[i] is not None else None,
-                    cur_len=cur_len, pages=pages,
+                    cache=cache, cur_len=cur_len, pages=layer_pages,
                     prefix_len=prefix_len, pos_base=pos_base)
-                new_attn.append(attn_cls(*nc) if nc is not None else None)
+                if pooled:
+                    new_pools[i] = nc
+                    new_attn.append(None)
+                else:
+                    new_attn.append(attn_cls(*nc) if nc is not None
+                                    else None)
                 if cfg.encoder is not None:
                     if decode:
                         ckv = cross_c
@@ -305,19 +331,26 @@ def run_decoder(params, cfg: ModelConfig, x, positions, *,
             elif spec.mixer == "ssm":
                 new_attn.append(None)
         ys = (tuple(new_attn), tuple(new_ssm), new_cross)
-        return (h, aux), ys
+        return (h, aux, tuple(new_pools)), ys
 
     if remat:
         body = jax.checkpoint(body)
 
-    attn_xs = (caches["attn"] if caches is not None
+    attn_xs = (caches["attn"] if caches is not None and not pooled
                else tuple(None for _ in pat))
     ssm_xs = (caches["ssm"] if caches is not None
               else tuple(None for _ in pat))
     cross_xs = caches["cross"] if caches is not None else None
-    xs = (params["blocks"], attn_xs, ssm_xs, cross_xs)
-    (h, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    reps = jnp.arange(n_rep, dtype=jnp.int32) if pooled else None
+    xs = (params["blocks"], attn_xs, ssm_xs, cross_xs, reps)
+    (h, aux, pools), ys = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), pools), xs)
     new_attn, new_ssm, new_cross = ys
+    if pooled:
+        new_attn = tuple(
+            None if c is None else
+            attn_cls(*(a.reshape(n_rep, -1, *a.shape[1:]) for a in c))
+            for c in pools)
 
     h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
 

@@ -1,6 +1,8 @@
 """Paged KV-cache subsystem: pool allocator, Pallas paged decode
 attention vs. oracle, paged engine parity with dense, and the O(pages)
 P->D insert path."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -397,3 +399,96 @@ def test_kv_dtype_follows_param_dtype(smollm, paged):
     eng = Engine(cfg, params, max_batch=1, max_len=32, paged=paged,
                  kv_dtype=jnp.float32)
     assert eng.caches["attn"][0].k.dtype == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# paged decode step: the pool rides the layer scan's carry
+# ---------------------------------------------------------------------------
+
+def _decode_cfg(name):
+    if name == "attn":
+        return get_config("smollm-135m").reduced()
+    if name == "swa":                       # window shorter than the run
+        return dataclasses.replace(get_config("mixtral-8x7b").reduced(),
+                                   sliding_window=6)
+    return get_config("jamba-v0.1-52b").reduced(n_layers=16)  # attn at 4/8
+
+
+def _decode_setup(name, b=2, page=4, max_len=32, mapped=4):
+    """Dense and paged caches for ``b`` slots decoding from position 0;
+    each slot maps ``mapped`` shuffled pages of its block table."""
+    from repro.models.model import init_params
+    from repro.models.transformer import make_caches
+    cfg = _decode_cfg(name)
+    assert cfg.n_repeats >= 2
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    dense = make_caches(cfg, b, max_len, dtype=jnp.float32)
+    n_pages = 1 + b * (max_len // page)
+    paged = make_caches(cfg, b, max_len, dtype=jnp.float32, layout="paged",
+                        page_size=page, n_pages=n_pages)
+    ids = np.random.RandomState(0).permutation(np.arange(1, n_pages))
+    tbl = np.zeros((b, max_len // page), np.int32)
+    tbl[:, :mapped] = ids[:b * mapped].reshape(b, mapped)
+    paged["pages"] = jnp.asarray(tbl)
+    return cfg, params, dense, paged
+
+
+def _step(cfg):
+    from repro.models.model import decode_forward
+    return jax.jit(lambda p, t, c: decode_forward(p, cfg, t, c))
+
+
+@pytest.mark.parametrize("name", ["attn", "swa", "hybrid"])
+def test_paged_decode_matches_dense_decode(name):
+    """Several decode steps through the paged path, over R >= 2 repeats
+    and across page boundaries, give the dense-cache decode's logits and
+    leave each repeat's tokens in that repeat's own pages."""
+    cfg, params, dense, paged = _decode_setup(name)
+    step = _step(cfg)
+    tok = jnp.asarray([3, 7], jnp.int32)
+    n_steps = 14                               # > window, > 3 pages of 4
+    for _ in range(n_steps):
+        ld, dense = step(params, tok, dense)
+        lp, paged = step(params, tok, paged)
+        np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
+                                   atol=1e-4, rtol=1e-4)
+        tok = jnp.argmax(ld, -1).astype(jnp.int32)
+    tbl = np.asarray(paged["pages"])
+    for dc, pc in zip(dense["attn"], paged["attn"]):
+        if dc is None:
+            continue
+        assert pc.k.shape[0] == cfg.n_repeats
+        for d, p in ((dc.k, pc.k), (dc.v, pc.v)):
+            rows = np.asarray(p)[:, tbl].reshape(
+                cfg.n_repeats, tbl.shape[0], -1, *p.shape[3:])
+            np.testing.assert_allclose(rows[:, :, :n_steps],
+                                       np.asarray(d)[:, :, :n_steps],
+                                       atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("name", ["attn", "swa", "hybrid"])
+def test_paged_decode_stray_write_hits_own_trash_page(name):
+    """A slot whose next token falls past its mapped pages writes into
+    its layer's own trash page (page 0 of repeat r, r * P of the
+    flattened pool) and leaves every other layer's pages untouched."""
+    page, mapped = 4, 2
+    cfg, params, _, paged = _decode_setup(name, page=page, mapped=mapped)
+    # slot 0 sits on its last mapped page, slot 1 one token past its
+    # mapped pages: its block-table entry there is the trash page
+    paged["len"] = jnp.asarray([mapped * page - 1, mapped * page + 1],
+                               jnp.int32)
+    before = [None if c is None else (np.asarray(c.k), np.asarray(c.v))
+              for c in paged["attn"]]
+    _, out = _step(cfg)(params, jnp.asarray([3, 7], jnp.int32), paged)
+    live = int(np.asarray(paged["pages"])[0, mapped - 1])
+    for prev, c in zip(before, out["attn"]):
+        if c is None:
+            continue
+        for old, new in zip(prev, (np.asarray(c.k), np.asarray(c.v))):
+            for r in range(cfg.n_repeats):
+                changed = {int(p) for p in np.nonzero(
+                    (old[r] != new[r]).any(axis=(1, 2, 3)))[0]}
+                assert changed == {0, live}, (r, changed)
+                # the stray token lands at its offset in the trash page
+                rows = np.nonzero((old[r, 0] != new[r, 0]).any(axis=(1, 2)))
+                assert rows[0].tolist() == [(mapped * page + 1) % page]

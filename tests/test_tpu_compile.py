@@ -10,6 +10,7 @@ decode slots, in bf16. Nothing here runs a kernel; results are checked
 against the ``ref.py`` oracles on the chip by ``chip_smoke.py``.
 """
 import dataclasses
+import math
 import os
 import re
 
@@ -30,10 +31,16 @@ from repro.serving.steps import make_decode_fn
 NQ, NKV, HD = 32, 8, 128
 PAGE, MAX_LEN, MAX_BATCH, CHUNK = 16, 4096, 16, 512
 POOL_PAGES = 1 + (MAX_BATCH + 1) * (MAX_LEN // PAGE)
+DEPTH = 8                                  # layers of the compiled step
 # one layer's K (or V) pool as the engine holds it, and the kernel's view
 POOL = f"bf16[{POOL_PAGES},{PAGE},{NKV},{HD}]"
 FLAT = f"bf16[{POOL_PAGES},{PAGE * NKV},{HD}]"
+# the decode step's stacked pool flattened over layers, and its view
+STACKED = f"bf16[{DEPTH * POOL_PAGES},{PAGE},{NKV},{HD}]"
+STACKED_FLAT = f"bf16[{DEPTH * POOL_PAGES},{PAGE * NKV},{HD}]"
+SLAB_ELEMS = POOL_PAGES * PAGE * NKV * HD
 _INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = (\S+?)\{.*?\} ([\w-]+)\((.*?)\)")
+_COMP = re.compile(r"%(\S+) \(.*\) -> .* \{$")
 
 
 @pytest.fixture(scope="module")
@@ -63,28 +70,45 @@ def _compile(fn, sharding, *shapes):
     return hlo
 
 
-def _assert_pool_view_is_bitcast(hlo):
-    """The paged kernel reads each pool through a bitcast of the
-    engine's (P, page, nkv, hd) array: no copy or transpose makes the
-    (P, page * nkv, hd) view, so the view moves no HBM bytes."""
+def _instructions(hlo):
+    """{name: (shape, opcode, operands, line)} of every array-valued HLO
+    instruction, over all computations."""
     instrs = {}
     for line in hlo.splitlines():
         m = _INSTR.match(line)
         if m:
             name, shape, op, operands = m.groups()
             instrs[name] = (shape, op, operands.split(", "), line)
+    return instrs
+
+
+def _assert_pool_view_is_bitcast(hlo, pool=POOL, flat=FLAT):
+    """The paged kernel reads each pool through a bitcast of the array
+    the engine holds, ``pool`` (P, page, nkv, hd): no copy or transpose
+    makes the ``flat`` (P, page * nkv, hd) view, so the view moves no HBM
+    bytes."""
+    instrs = _instructions(hlo)
     views = [o.lstrip("%")
              for shape, op, operands, line in instrs.values()
              if 'custom_call_target="tpu_custom_call"' in line
              for o in operands
-             if instrs.get(o.lstrip("%"), ("",))[0] == FLAT]
+             if instrs.get(o.lstrip("%"), ("",))[0] == flat]
     assert len(views) == 2, views                      # K and V
     for v in views:
         _, op, (src,), _ = instrs[v]
         assert op == "bitcast", instrs[v][3]
-        assert instrs[src.lstrip("%")][0] == POOL, instrs[v][3]
+        assert instrs[src.lstrip("%")][0] == pool, instrs[v][3]
     assert not [line for shape, op, _, line in instrs.values()
-                if op in ("copy", "transpose") and shape in (POOL, FLAT)]
+                if op in ("copy", "transpose") and shape in (pool, flat)]
+
+
+def _pool_sized(shape):
+    """An array of pages (page, nkv, hd) or of their kernel view
+    (page * nkv, hd), at least as large as one layer's pool slab."""
+    dims = [int(d) for d in re.search(r"\[(.*)\]", shape).group(1).split(",")
+            if d]
+    return math.prod(dims) >= SLAB_ELEMS and (
+        dims[-3:] == [PAGE, NKV, HD] or dims[-2:] == [PAGE * NKV, HD])
 
 
 @pytest.mark.parametrize("b", [1, 2])
@@ -117,7 +141,7 @@ def decode_step_hlo(one_chip):
     it, compiled: llava-next-mistral-7b at published widths, 8 layers,
     bf16, the compiled kernels."""
     cfg = dataclasses.replace(get_config("llava-next-mistral-7b"),
-                              n_layers=8)
+                              n_layers=DEPTH)
     params = jax.eval_shape(
         lambda key: init_params(cfg, key, dtype=jnp.bfloat16),
         jax.random.PRNGKey(0))
@@ -139,9 +163,43 @@ def decode_step_hlo(one_chip):
 
 
 def test_paged_decode_step_compiles_for_v5e(decode_step_hlo):
-    """Inside the layer scan too, each layer's pool slice reaches the
-    kernel through a bitcast."""
-    _assert_pool_view_is_bitcast(decode_step_hlo)
+    """Inside the layer scan the kernel reads the whole stacked pool,
+    flattened over layers (its block table is offset to the layer's
+    pages), through a bitcast: no layer's slab is sliced out for it."""
+    _assert_pool_view_is_bitcast(decode_step_hlo, STACKED, STACKED_FLAT)
+
+
+def test_paged_decode_step_moves_no_pool_sized_buffer(decode_step_hlo):
+    """A decode step writes one token per slot and layer into the pool,
+    in place: no copy, transpose, slice, update-slice or fusion makes an
+    array as large as one layer's pool slab. The one pool-sized result
+    allowed is the in-place scatter of the new tokens, a fusion whose
+    root is a ``scatter`` into the carried pool (copy insertion would
+    have put an explicit ``copy`` before it if it could not alias)."""
+    instrs = _instructions(decode_step_hlo)
+    roots, comp = {}, None
+    for line in decode_step_hlo.splitlines():
+        m = _COMP.match(line)
+        if m:
+            comp = m.group(1)
+        elif line.lstrip().startswith("ROOT ") and (r := _INSTR.match(line)):
+            roots[comp] = r.group(3)
+    moved = []
+    for name, (shape, op, operands, line) in instrs.items():
+        if (op not in ("copy", "transpose", "dynamic-slice",
+                       "dynamic-update-slice", "fusion")
+                or not _pool_sized(shape)):
+            continue
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.-]+)", line).group(1)
+            src = instrs.get(operands[0].lstrip("%"), ("",))[0]
+            if roots.get(called) == "scatter" and src == shape:
+                continue
+        moved.append(line.strip()[:200])
+    assert not moved, moved
+    scatters = [n for n, (shape, op, _, _) in instrs.items()
+                if op == "fusion" and shape == STACKED]
+    assert len(scatters) == 2, scatters                # K and V
 
 
 def test_paged_decode_step_names_its_kernel(decode_step_hlo):
