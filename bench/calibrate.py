@@ -33,7 +33,7 @@ def readings_over_seeds(cell, seeds):
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import check, harness, reference
+    from bench import check, harness
     from repro.core.cluster import EPDCluster
 
     doc, traffic = cell.config, cell.traffic
@@ -43,9 +43,10 @@ def readings_over_seeds(cell, seeds):
     image_tokens = doc["model"].get("image_tokens", 0)
     gen = harness.generator(traffic)
     sizes = dict(image_tokens=image_tokens, max_len=serving["max_len"])
-    arch = reference.Arch.from_doc(doc["model"])
+    model = harness.model_module(doc)
+    arch = model.Arch.from_doc(doc["model"])
 
-    params = harness.make_weights(cfg, seeds[0], dtype)
+    params = harness.make_weights(cfg, seeds[0], dtype, model)
     cluster = EPDCluster(cfg, params, **serving)
     warm = [harness.to_request(s, image_tokens) for s in gen.warmup(
         traffic, vocab=cfg.vocab, page=serving["page_size"], **sizes)]
@@ -54,7 +55,7 @@ def readings_over_seeds(cell, seeds):
                + cluster.encode_engines)
     for seed in seeds:
         t0 = time.perf_counter()
-        params = harness.make_weights(cfg, seed, dtype)
+        params = harness.make_weights(cfg, seed, dtype, model)
         for e in engines:
             e.params = params
         pc = cluster.prefill_engine.prefix_cache
@@ -70,7 +71,7 @@ def readings_over_seeds(cell, seeds):
         rng = np.random.Generator(np.random.PCG64(
             np.random.SeedSequence([int(seed), 2])))
         sample = check.sample(served, rng, traffic["check_tokens"])
-        got = check.readings(params, arch, sample,
+        got = check.readings(params, model, arch, sample,
                              length=serving["max_len"],
                              n_read=int(traffic["output_tokens"]["max"]),
                              control=True)

@@ -2,12 +2,13 @@
 
 After the window: a sample of the requests the window finished, drawn
 from the seed with the longest among them, until it holds
-``check_tokens`` served tokens. The reference runs once over each
-prompt with its served tokens; at every position that produced a served
-token it reads how far the served token's logit lies below the
-reference's best logit there. The widest such gap over the sample is
-compared with the configuration's limit. A greedy server that computes
-what the reference computes reads 0 up to rounding near ties.
+``check_tokens`` served tokens. The reference (the plain one of the
+configuration's model family, ``bench/models/<reference>.py``) runs
+once over each prompt with its served tokens; at every position that
+produced a served token it reads how far the served token's logit lies
+below the reference's best logit there. The widest such gap over the
+sample is compared with the configuration's limit. A greedy server that
+computes what the reference computes reads 0 up to rounding near ties.
 """
 from __future__ import annotations
 
@@ -15,8 +16,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
-
-from bench import reference
 
 
 @dataclass
@@ -88,25 +87,26 @@ def gaps(ref_logits: np.ndarray, tokens: Sequence[int]) -> np.ndarray:
     return ref_logits.max(-1) - ref_logits[rows, np.asarray(tokens)]
 
 
-def readings(params, arch: reference.Arch, reqs: List[Served], *,
-             length: int, n_read: int, control: bool = False
-             ) -> Dict[str, float]:
-    """``max_logit_gap`` of the served tokens over ``reqs``; with
-    ``control`` also that of the tokens the fp8 control would put first
-    at the same positions (``control_max_logit_gap``)."""
+def readings(params, model, arch, reqs: List[Served], *, length: int,
+             n_read: int, control: bool = False) -> Dict[str, float]:
+    """``max_logit_gap`` of the served tokens over ``reqs``, by the
+    reference of the configuration's model family (``model.logits`` on
+    ``arch``, its ``Arch``); with ``control`` also that of the tokens the
+    fp8 control would put first at the same positions
+    (``control_max_logit_gap``)."""
     worst = ctl = 0.0
     n = 0
     for r in reqs:
         kw = dict(seq=r.sequence(), read=r.read_positions(),
                   image=r.image, image_pos=r.image_pos, length=length,
                   n_read=n_read)
-        ref = reference.logits(params, arch, **kw)
+        ref = model.logits(params, arch, **kw)
         if not np.isfinite(ref).all():
             raise FloatingPointError("reference logits are not finite")
         worst = max(worst, float(gaps(ref, r.output).max()))
         n += len(r.output)
         if control:
-            low = reference.logits(params, arch, quant="fp8", **kw)
+            low = model.logits(params, arch, quant="fp8", **kw)
             ctl = max(ctl, float(gaps(ref, low.argmax(-1)).max()))
     out = {"max_logit_gap": worst, "tokens_compared": n,
            "requests_compared": len(reqs)}

@@ -6,9 +6,12 @@ Everything a cell is made of is found by name: the cell in
 its traffic in ``bench/traffic/<traffic>.json`` (whose ``generator``
 names ``bench/traffic/gen_<generator>.py``), each per-layer metric in
 ``bench/metrics/<metric>.py`` (end-to-end and per-layer alike: each
-reads a :class:`Context`) and the chip's peaks in ``bench/peaks.json``.
-Adding a cell, a configuration, a mix or a metric adds files and edits
-none.
+reads a :class:`Context`), the chip's peaks in ``bench/peaks.json``,
+and the configuration's model family in ``bench/models/<reference>.py``
+(its ``"reference"`` key): the plain reference that decides
+``correct``, the work counts the readers divide by and the rules for
+any weight init beyond the plain ones. Adding a cell, a configuration,
+a family, a mix or a metric adds files and edits none.
 
 The system under test is ``repro.core.cluster.EPDCluster`` driven through
 ``run_continuous``: each burst is one call, submitted at once, and the
@@ -29,14 +32,14 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 REPO = BENCH.parent
 
-from bench import check, reference, stats, trace_reduce, work  # noqa: E402
+from bench import check, stats, trace_reduce  # noqa: E402
 
 # a program lowered to MLIR: a new shape, compiled or read from the cache
 _LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -82,8 +85,10 @@ def resolve(name: str, root: Path = REPO) -> Cell:
 
 def load_module(path: Path):
     """Import a file of the benchmark by its path (names may hold dots)."""
+    path = path.resolve()
+    rel = path.relative_to(BENCH) if path.is_relative_to(BENCH) else path
     name = "bench._" + "".join(c if c.isalnum() else "_" for c in
-                               str(path.relative_to(BENCH).with_suffix("")))
+                               str(rel.with_suffix("")))
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -107,6 +112,16 @@ def reader(metric: str):
     return load_module(BENCH / "metrics" / f"{metric}.py")
 
 
+def model_module(doc: dict, models: Path = BENCH / "models"):
+    """The configuration's model family, ``<models>/<reference>.py``
+    (see ``bench/models/dense.py`` for what it provides)."""
+    path = models / f"{doc['reference']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model family {doc['reference']!r}: "
+                                f"{path} is missing")
+    return load_module(path)
+
+
 def peaks(device_kind: str) -> dict:
     table = read_json(BENCH / "peaks.json")
     if device_kind not in table:
@@ -117,8 +132,35 @@ def peaks(device_kind: str) -> dict:
 
 # ---- the model -------------------------------------------------------------
 
+def _fields(obj) -> set:
+    return ({f.name for f in dataclasses.fields(obj)}
+            if dataclasses.is_dataclass(obj) else set())
+
+
+def _with(cur, path: str, value, full: str):
+    """What a field holding ``cur`` holds once the field at the dotted
+    ``path`` below it (``""``: the field itself) is set to the scalar
+    ``value``. A field that holds a group (a dataclass, or a tuple such
+    as ``pattern``) is not set whole."""
+    if not path:
+        if dataclasses.is_dataclass(cur) or isinstance(cur, tuple):
+            raise TypeError(f"program override {full!r} names a group; "
+                            f"set its fields one by one")
+        return value
+    head, _, rest = path.partition(".")
+    if head not in _fields(cur):
+        raise KeyError(f"program override {full!r}: {type(cur).__name__} "
+                       f"has no field {head!r}")
+    return dataclasses.replace(
+        cur, **{head: _with(getattr(cur, head), rest, value, full)})
+
+
 def model_config(doc: dict):
-    """The registry's ModelConfig with every size of the file applied."""
+    """The registry's ModelConfig with every size of the file applied:
+    the dense keys of ``"model"``, then the ``"program"`` block's dotted
+    overrides of the config's fields (``{"moe.n_experts": 9}``), each of
+    which has to name a field that exists and give it a number, a string
+    or a boolean."""
     from repro.configs import get_config
     m = doc["model"]
     base = get_config(doc["registry"])
@@ -135,15 +177,17 @@ def model_config(doc: dict):
     elif base.frontend is not None:
         raise ValueError(f"{doc['registry']} has an image frontend; the "
                          f"configuration must give its sizes")
+    for path, value in doc.get("program", {}).items():
+        if not isinstance(value, (bool, int, float, str)):
+            raise TypeError(f"program override {path!r}: {value!r} is not "
+                            f"a number, a string or a boolean")
+        head, _, rest = path.partition(".")
+        if head not in _fields(base):
+            raise KeyError(f"program override {path!r}: ModelConfig has "
+                           f"no field {head!r}")
+        kw[head] = _with(kw.get(head, getattr(base, head)), rest, value,
+                         path)
     return dataclasses.replace(base, **kw)
-
-
-def dims(doc: dict) -> work.Dims:
-    m = doc["model"]
-    return work.Dims(m["num_hidden_layers"], m["hidden_size"],
-                     m["num_attention_heads"], m["num_key_value_heads"],
-                     m["head_dim"], m["intermediate_size"], m["vocab_size"],
-                     m.get("vision_feature_dim", 0))
 
 
 def seed_key(seed: int, stream: int):
@@ -153,22 +197,27 @@ def seed_key(seed: int, stream: int):
                               int(b) & 0x7FFFFFFF)
 
 
-def make_weights(cfg, seed: int, dtype):
+def make_weights(cfg, seed: int, dtype, model):
     """Every weight from ``seed`` in one jitted call, on the device, in
-    the served dtype: normal with std 1/sqrt(fan_in), norms 1."""
+    the served dtype: normal with std 1/sqrt(fan_in), norms 1, and any
+    other init by the model family's ``init_rules``."""
     import jax
     import jax.numpy as jnp
     from repro.models.params import ParamSpec, param_structure
 
     leaves, tree = jax.tree.flatten(
         param_structure(cfg), is_leaf=lambda x: isinstance(x, ParamSpec))
+    rules = getattr(model, "init_rules", {})
 
     def one(spec, k):
         if spec.init in ("ones", "zeros"):
             fill = 1.0 if spec.init == "ones" else 0.0
             return jnp.full(spec.shape, fill, dtype)
         if spec.init != "normal":
-            raise ValueError(f"no weight rule for init {spec.init!r}")
+            if spec.init not in rules:
+                raise ValueError(f"no weight rule for init {spec.init!r} "
+                                 f"in {model.__file__}")
+            return rules[spec.init](k, spec.shape, dtype)
         fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
         return (jax.random.normal(k, spec.shape, jnp.float32)
                 * (1.0 / math.sqrt(fan_in))).astype(dtype)
@@ -308,14 +357,21 @@ class Traced:
     (0: with the first burst, its encodes included) and stops at the
     first iteration ``length`` seconds later, each time after the device
     has finished what was queued, and snapshots the counters both
-    times."""
+    times. Given ``bursts`` in place of ``length``, it holds whole
+    bursts: it starts at a burst's submit and stops at the submit that
+    many bursts later (or at the window's end)."""
 
     def __init__(self, cluster, rec: Recorder, out: Path, after: float,
-                 length: float):
+                 length: Optional[float] = None,
+                 bursts: Optional[int] = None):
         import jax
         import jax.numpy as jnp
+        if (length is None) == (bursts is None):
+            raise ValueError("a mix gives trace_s or trace_bursts, not "
+                             "both or neither")
         self.cluster, self.rec, self.out = cluster, rec, out
-        self.after, self.length = after, length
+        self.after, self.length, self.bursts = after, length, bursts
+        self.submits = 0
         self.t0 = self.t1 = None
         self.c0 = self.c1 = None
         self.gauges: Dict[str, float] = {}
@@ -335,18 +391,24 @@ class Traced:
         import jax.numpy as jnp
         jax.block_until_ready(self._sync(jnp.zeros((), jnp.int32)))
 
-    def __call__(self, now: float) -> None:
+    def __call__(self, now: float, submit: bool = False) -> None:
         import jax
         if self.t0 is None:
-            if now - self.rec.window.start >= self.after:
+            if (now - self.rec.window.start >= self.after
+                    and (submit or self.bursts is None)):
                 self._quiet()
                 shutil.rmtree(self.out, ignore_errors=True)
                 jax.profiler.start_trace(str(self.out))
                 self.c0 = self.counters()
                 self.rec.lengths = []
                 self.t0 = time.perf_counter()
-        elif self.t1 is None and now - self.t0 >= self.length:
-            self.stop()
+        elif self.t1 is None and self.bursts is None:
+            if now - self.t0 >= self.length:
+                self.stop()
+        elif self.t1 is None and submit:
+            self.submits += 1
+            if self.submits >= self.bursts:
+                self.stop()
 
     def stop(self) -> None:
         import jax
@@ -418,7 +480,7 @@ class Context:
     setup_s: float
     decode_steps: int = 0                 # whole window
     occupancy: Optional[float] = None     # active slots / slots, mean
-    dims: Optional[work.Dims] = None
+    work: Any = None                      # the family's work(doc) counts
     peaks: Optional[dict] = None
     image_tokens: int = 0
     window_s: Optional[float] = None      # traced part, host clock
@@ -439,7 +501,7 @@ class Context:
         return self.trace.by_kind.get(f"{program}/{kind}", 0.0)
 
 
-def trace_context(ctx: Context, cell: Cell, tr: Traced,
+def trace_context(ctx: Context, cell: Cell, model, tr: Traced,
                   events: List[trace_reduce.Event],
                   red: trace_reduce.Reduced, reqs_prompt: Dict[int, int],
                   device_kind: str) -> None:
@@ -451,7 +513,7 @@ def trace_context(ctx: Context, cell: Cell, tr: Traced,
     ctx.chunks = _chunks(tr.cluster.tracer.spans, reqs_prompt,
                          c0["bench.spans"], c1["bench.spans"])
     ctx.decode_lengths = tr.lengths or []
-    ctx.dims = dims(cell.config)
+    ctx.work = model.work(cell.config)
     ctx.peaks = peaks(device_kind)
     ctx.image_tokens = cell.config["model"].get("image_tokens", 0)
     ctx.window_s = tr.t1 - tr.t0
@@ -483,14 +545,15 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     doc, traffic = cell.config, cell.traffic
     serving = doc["serving"]
     cfg = model_config(doc)
+    model = model_module(doc)
     dtype = jnp.dtype(doc["dtype"])
     image_tokens = doc["model"].get("image_tokens", 0)
     gen = generator(traffic)
     sizes = dict(image_tokens=image_tokens, max_len=serving["max_len"])
 
-    params = make_weights(cfg, seed, dtype)
+    params = make_weights(cfg, seed, dtype, model)
     jax.block_until_ready(params)
-    tracer = Tracer(enabled=True, decode_sample=1 << 62) if trace else None
+    tracer = Tracer(enabled=True) if trace else None
     cluster = EPDCluster(cfg, params, tracer=tracer, **serving)
 
     # set-up: every program shape the mix produces, then the eager ops
@@ -516,7 +579,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     tr = None
     if trace:
         tr = Traced(cluster, rec, out_dir / "trace",
-                    traffic["trace_after_s"], traffic["trace_s"])
+                    traffic["trace_after_s"], traffic.get("trace_s"),
+                    traffic.get("trace_bursts"))
         rec.then = tr
     watch.start()
     served: List[check.Served] = []
@@ -526,7 +590,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         reqs = [to_request(s, image_tokens) for s in next(bursts)]
         prompts.update((r.request_id, r.total_prompt_len) for r in reqs)
         if tr is not None:
-            tr(time.perf_counter())   # a trace may start with the burst
+            # a trace may start, or stop, with the burst
+            tr(time.perf_counter(), submit=True)
         rec.begin(reqs, time.perf_counter())
         done = cluster.run_continuous(reqs, on_step=rec)
         ids = {r.request_id for r in done}
@@ -570,7 +635,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         shutil.rmtree(tr.out, ignore_errors=True)
         device["busy_s"] = red.busy_s
         device["window_s"] = tr.t1 - tr.t0
-        trace_context(ctx, cell, tr, events, red, prompts, device["kind"])
+        trace_context(ctx, cell, model, tr, events, red, prompts,
+                      device["kind"])
         result["breakdown"] = {"device_ops": red.top_ops,
                                "idle_gaps": red.idle_gaps}
         calls = {k: v for k, v in red.by_kind.items() if "custom-call" in k}
@@ -581,12 +647,13 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     # the reference runs once the window's state is gone
     del cluster, tr, warm, done, rec
     gc.collect()
-    arch = reference.Arch.from_doc(doc["model"])
+    arch = model.Arch.from_doc(doc["model"])
     rng = np.random.Generator(np.random.PCG64(
         np.random.SeedSequence([int(seed), 2])))
     sample = check.sample(served, rng, traffic["check_tokens"])
     t_ref = time.perf_counter()
-    got = check.readings(params, arch, sample, length=serving["max_len"],
+    got = check.readings(params, model, arch, sample,
+                         length=serving["max_len"],
                          n_read=int(traffic["output_tokens"]["max"]))
     log(f"reference: {got['requests_compared']} requests, "
         f"{got['tokens_compared']} tokens in {time.perf_counter() - t_ref!r}"

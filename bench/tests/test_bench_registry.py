@@ -6,7 +6,7 @@ import sys
 
 import pytest
 
-from bench import harness, reference
+from bench import harness
 
 DOC = harness.read_json(harness.REPO / "BENCHMARK.json")
 CELLS = [w["name"] for w in DOC["workloads"]]
@@ -39,9 +39,15 @@ def test_config_sizes_reach_the_program(name):
         m["num_hidden_layers"], m["hidden_size"], m["num_attention_heads"],
         m["num_key_value_heads"], m["head_dim"], m["intermediate_size"],
         m["vocab_size"], m["rms_norm_eps"])
-    assert reference.Arch.from_doc(m).n_layers == cfg.n_layers
+    model = harness.model_module(doc)
+    assert model.Arch.from_doc(m).n_layers == cfg.n_layers
     for k in doc["reduced"]:
         assert m[k] != doc["published"][k]
+    for path, value in doc.get("program", {}).items():
+        got = cfg
+        for part in path.split("."):
+            got = getattr(got, part)
+        assert got == value, path
 
 
 def test_unknown_device_kind_is_an_error():

@@ -67,8 +67,11 @@ def test_stall_share_reads_the_scheduler_counters():
     read = harness.reader("prefill_stall_pct").read
     assert read(_ctx({"sched_stalls_total": 45.0,
                       "sched_chunks_total": 5.0})) == pytest.approx(90.0)
-    # a program without the stall counter reads nothing
-    assert read(_ctx({"sched_chunks_total": 5.0})) is None
+    # the stall counter is made at the first stall: chunks without it
+    # read no stall
+    assert read(_ctx({"sched_chunks_total": 5.0})) == 0.0
+    assert read(_ctx({"sched_stalls_total": 2.0})) == pytest.approx(100.0)
+    assert read(_ctx({})) is None
     assert read(_ctx({"sched_stalls_total": 0.0})) is None
     assert read(_ctx(None)) is None
 
@@ -123,3 +126,34 @@ def test_recording_cuts_whole_decode_steps():
     runs = [r for r in rows if r[0] == "Modules"
             and T.program(r[1]) == "jit_decode_fn"]
     assert len(runs) == 2
+
+
+def test_decode_sample_leaves_the_served_spans_alone(tiny_cell):
+    """``Tracer.decode_sample`` thins only the simulator's spans: the
+    real cluster records the same spans with it at its default and at
+    one in 2**62 decode steps."""
+    import jax.numpy as jnp
+    from repro.core.cluster import EPDCluster
+    from repro.core.telemetry import Tracer
+
+    cell = tiny_cell("deepseek-chat-batch48")
+    doc, traffic = cell.config, cell.traffic
+    cfg = harness.model_config(doc)
+    params = harness.make_weights(cfg, 3, jnp.dtype(doc["dtype"]),
+                                  harness.model_module(doc))
+    gen = harness.generator(traffic)
+
+    def spans(tracer):
+        specs = next(gen.bursts(traffic, 3, vocab=cfg.vocab, image_tokens=0,
+                                max_len=doc["serving"]["max_len"]))[:3]
+        reqs = [harness.to_request(s, 0) for s in specs]
+        first = min(r.request_id for r in reqs)
+        EPDCluster(cfg, params, tracer=tracer,
+                   **doc["serving"]).run_continuous(reqs)
+        return [(s.name, s.track, s.parent, s.attrs,
+                 None if s.request_id is None else s.request_id - first)
+                for s in tracer.spans]
+
+    got = spans(Tracer(enabled=True))
+    assert any(name == "decode.step" for name, *_ in got)
+    assert spans(Tracer(enabled=True, decode_sample=1 << 62)) == got

@@ -99,6 +99,38 @@ def test_a_traced_run_reads_its_layers(tiny_cell, tmp_path, monkeypatch):
     assert not (tmp_path / "trace").exists()      # the trace is removed
 
 
+def test_a_traced_part_holds_whole_bursts(tiny_cell, tmp_path, monkeypatch):
+    """With ``trace_bursts`` the traced part starts at a burst's submit
+    and stops at the submit that many bursts later: it admits exactly
+    those bursts' requests, though the window serves more."""
+    import json
+    import re
+    import time
+    v5e = harness.peaks("TPU v5 lite")
+    monkeypatch.setattr(harness, "peaks", lambda kind: v5e)
+    cell = tiny_cell("deepseek-longprompt-burst16")
+    cell.traffic.update(trace_after_s=0.0, trace_bursts=2)
+    assert "trace_s" not in cell.traffic
+    lines = []
+    out = harness.run_cell(cell, seed=2**31 + 9, seconds=2.0, trace=True,
+                           t_process=time.perf_counter(), out_dir=tmp_path,
+                           log=lines.append)
+    assert out["correct"], out["check"]
+    text = "\n".join(lines)
+    assert int(re.search(r"(\d+) bursts", text).group(1)) >= 3
+    counts = json.loads(re.search(r"traced counts (.*)", text).group(1))
+    assert counts["sched_admissions_total"] == 2 * cell.traffic["burst"]
+    # no stall in a text mix: the stall share reads 0, not nothing
+    assert out["metrics"]["prefill_stall_pct"]["value"] == 0.0
+
+
+def test_a_mix_gives_one_traced_length():
+    with pytest.raises(ValueError, match="trace_s or trace_bursts"):
+        harness.Traced(None, None, None, 0.0, 1.0, 2)
+    with pytest.raises(ValueError, match="trace_s or trace_bursts"):
+        harness.Traced(None, None, None, 0.0)
+
+
 def _recorded():
     doc = harness.read_json(HERE / "trace_v5e.json")
     return [T.Event(doc["device"], "XLA " + line, name, float(s), float(d))

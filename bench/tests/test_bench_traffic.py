@@ -6,7 +6,7 @@ import pytest
 
 from bench import harness
 
-MIXES = ["sharegpt4o-burst16", "chat-longout-batch48"]
+MIXES = ["sharegpt4o-burst16", "chat-longout-batch48", "longprompt-burst16"]
 SIZES = dict(vocab=32064, image_tokens=2880, max_len=4096)
 
 
